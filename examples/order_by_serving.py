@@ -4,17 +4,18 @@ pod-served model via the ModelOracle — the paper's production deployment
 shape (the oracle is OUR model, not an external API).
 
 Run:  PYTHONPATH=src python examples/order_by_serving.py [--arch stablelm-1.6b]
+      [--full]   (published widths; the engine is sized for one TPU v5e)
 """
 import argparse
 import time
 
 import jax
 
-from repro.configs import get_reduced, list_archs
 from repro.core import as_keys, llm_order_by
 from repro.core.oracles.model_oracle import ModelOracle
-from repro.models import LM
-from repro.serving import BatchScheduler, ServeEngine
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import add_model_args, build_engine, build_lm
+from repro.serving import BatchScheduler
 
 PASSAGES = [
     "bmt stands for bone marrow transplant, a medical procedure",
@@ -34,16 +35,15 @@ PASSAGES = [
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="stablelm-1.6b", choices=list_archs())
+    add_model_args(ap)
     ap.add_argument("--limit", type=int, default=5)
     args = ap.parse_args()
 
     # 1) host the model
-    cfg = get_reduced(args.arch)
-    lm = LM(cfg)
-    params = lm.init(jax.random.PRNGKey(0))
-    engine = ServeEngine(lm, params, max_new_tokens=12)
-    print(f"serving {cfg.name}: "
+    enable_compile_cache()
+    lm, params = build_lm(args.arch, full=not args.reduced)
+    engine = build_engine(lm, params, full=not args.reduced)
+    print(f"serving {lm.cfg.name}: "
           f"{sum(x.size for x in jax.tree.leaves(params)):,} params")
 
     # 2) batched request path (the scheduler the ORDER BY operators ride on)

@@ -108,7 +108,9 @@ class SimulatedOracle(Oracle):
         # antisymmetric by canonical pair ordering
         lo, hi = (a, b) if a.uid <= b.uid else (b, a)
         rng = self._rng("compare", lo.uid, hi.uid, criteria)
-        p_hi_wins = 1.0 / (1.0 + math.exp(-(hi.latent - lo.latent) / self.profile.compare_temp))
+        z = -(hi.latent - lo.latent) / self.profile.compare_temp
+        # math.exp overflows above ~709.78, where the win chance is 0 anyway
+        p_hi_wins = 1.0 / (1.0 + math.exp(z)) if z < 709.0 else 0.0
         hi_wins = rng.random() < p_hi_wins
         if hi_wins:
             return 1 if a is hi or a.uid == hi.uid else -1

@@ -2,17 +2,14 @@
 
 Dispatch policy: compiled Pallas on TPU, interpret-mode (Python-executed
 kernel body) elsewhere — so the SAME kernel code is validated on CPU CI and
-deployed on pods.  ``force_interpret`` / ``force_ref`` env knobs support
-A/B-ing kernels against their pure-jnp oracles in benchmarks.
+deployed on pods.  There is no override: on a TPU the compiled kernel runs.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
 
-from . import ref
 from .borda_count import borda_count as _borda
 from .decode_attention import decode_attention as _decode
 from .flash_attention import flash_attention as _flash
@@ -28,13 +25,7 @@ def on_tpu() -> bool:
 
 
 def use_interpret() -> bool:
-    if os.environ.get("REPRO_FORCE_INTERPRET"):
-        return True
     return not on_tpu()
-
-
-def use_ref() -> bool:
-    return bool(os.environ.get("REPRO_FORCE_REF"))
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "q_offset",
@@ -43,17 +34,12 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_offset: int = 0, block_q: int = 128, block_k: int = 128):
     """``q_offset`` > 0 runs suffix-only (chunked) prefill over prepended
     KV — the kernel-level counterpart of the serving prefix-KV cache."""
-    if use_ref():
-        return ref.attention_ref(q, k, v, causal=causal, window=window,
-                                 q_offset=q_offset)
     return _flash(q, k, v, causal=causal, window=window, q_offset=q_offset,
                   block_q=block_q, block_k=block_k, interpret=use_interpret())
 
 
 @partial(jax.jit, static_argnames=("block_k",))
 def decode_attention(q, k_cache, v_cache, pos, *, block_k: int = 256):
-    if use_ref():
-        return ref.decode_attention_ref(q, k_cache, v_cache, pos)
     return _decode(q, k_cache, v_cache, pos, block_k=block_k,
                    interpret=use_interpret())
 
@@ -67,9 +53,6 @@ def paged_decode_attention(q, k_pool, v_pool, tables, ctx_len):
     (layers.paged_decode_attention_dense, which the bit-identity contract
     needs); this is the pod-serving variant validated against the same
     ref oracle."""
-    if use_ref():
-        return ref.paged_decode_attention_ref(q, k_pool, v_pool, tables,
-                                              ctx_len)
     return _paged(q, k_pool, v_pool, tables, ctx_len,
                   interpret=use_interpret())
 
@@ -77,8 +60,6 @@ def paged_decode_attention(q, k_pool, v_pool, tables, ctx_len):
 @partial(jax.jit, static_argnames=("k", "block_n"))
 def topk_scores(scores, k: int, *, block_n: int = 1024):
     """Two-stage top-k: blocked Pallas candidates + final jnp reduce."""
-    if use_ref():
-        return ref.topk_ref(scores, k)
     bv, bi = _topk(scores, k, block_n=block_n, interpret=use_interpret())
     cand_v, cand_i = bv.reshape(-1), bi.reshape(-1)
     vals, sel = jax.lax.top_k(cand_v, k)
@@ -88,30 +69,21 @@ def topk_scores(scores, k: int, *, block_n: int = 1024):
 @partial(jax.jit, static_argnames=("n_items", "block_items", "block_ballots"))
 def borda_count(ballots, n_items: int, *, block_items: int = 128,
                 block_ballots: int = 8):
-    if use_ref():
-        return ref.borda_ref(ballots, n_items)
     return _borda(ballots, n_items, block_items=block_items,
                   block_ballots=block_ballots, interpret=use_interpret())
 
 
 @partial(jax.jit, static_argnames=("block_d", "chunk"))
 def ssm_scan(x, dt, b_t, c_t, a, *, block_d: int = 256, chunk: int = 64):
-    if use_ref():
-        return ref.ssm_scan_ref(x, dt, b_t, c_t, a)[0]
     return _ssm(x, dt, b_t, c_t, a, block_d=block_d, chunk=chunk,
                 interpret=use_interpret())
 
 
 @partial(jax.jit, static_argnames=("chunk",))
 def mlstm_scan(q, k, v, i_g, f_g, *, chunk: int = 64):
-    if use_ref():
-        return ref.mlstm_ref(q, k, v, i_g, f_g)
     return _mlstm(q, k, v, i_g, f_g, chunk=chunk, interpret=use_interpret())
 
 
 @partial(jax.jit, static_argnames=("k", "block_t"))
 def moe_gating(logits, k: int, *, block_t: int = 256):
-    if use_ref():
-        idx, gates, pos, _ = ref.moe_gating_ref(logits, k, capacity=1 << 30)
-        return idx, gates, pos
     return _moe_gate(logits, k, block_t=block_t, interpret=use_interpret())

@@ -45,12 +45,18 @@ def _kernel(tables_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32) * scale           # (G, hd)
     k = k_ref[0, 0].astype(jnp.float32)                   # (bs, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    pos = ki * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    valid = pos < ctx_ref[bi]                             # (1, bs)
-    k = jnp.where(valid.T, k, 0.0)
-    v = jnp.where(valid.T, v, 0.0)
-    s = q @ k.T                                           # (G, bs)
-    s = jnp.where(valid, s, NEG_INF)
+    # the slot mask is needed as a row (bs on lanes, for the scores) and as
+    # a column (bs on sublanes, for the K/V tiles); both come from their
+    # own iota — Mosaic cannot transpose an i1 vector
+    ctx = ctx_ref[bi]
+    valid_row = (ki * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+                 < ctx)                                   # (1, bs)
+    valid_col = (ki * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+                 < ctx)                                   # (bs, 1)
+    k = jnp.where(valid_col, k, 0.0)
+    v = jnp.where(valid_col, v, 0.0)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bs)
+    s = jnp.where(valid_row, s, NEG_INF)
     m_prev = m_scr[...]
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_cur)

@@ -98,22 +98,13 @@ def moe_ffn_sharded(p, x, spec: MoESpec, mesh, dp_axes, model_axis: str):
 
     from jax.sharding import PartitionSpec as P
 
-    if hasattr(jax, "shard_map"):                 # jax >= 0.6
-        _shard_map = jax.shard_map
-    else:                                         # jax 0.4.x fallback
-        from jax.experimental.shard_map import shard_map as _shard_map
-    import inspect
-    _sig = inspect.signature(_shard_map).parameters
-    _nocheck = ({"check_vma": False} if "check_vma" in _sig
-                else {"check_rep": False} if "check_rep" in _sig else {})
-
     x_spec = P(dp_axes, None, None)
     w_col = P(None, None, model_axis)   # (E, D, F): F sharded
     w_row = P(None, model_axis, None)   # (E, F, D): F sharded
 
-    @_partial(_shard_map, mesh=mesh,
+    @_partial(jax.shard_map, mesh=mesh,
               in_specs=(x_spec, P(), w_col, w_col, w_row),
-              out_specs=x_spec, **_nocheck)
+              out_specs=x_spec, check_vma=False)
     def _local(xs, router, w_gate, w_up, w_down):
         params = {"router": router, "w_gate": w_gate, "w_up": w_up,
                   "w_down": w_down}

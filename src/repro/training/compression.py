@@ -67,15 +67,13 @@ def ef_allreduce(mesh, axis_names, x_q, scale):
     """Explicit compressed all-reduce of one leaf over ``axis_names``:
     int8 payload widened to int32, psum'd, then dequantized and averaged.
     The wire format is int8 (the int32 widening models the accumulator)."""
-    from jax.experimental.shard_map import shard_map
-
     n = 1
     for a in axis_names:
         n *= mesh.shape[a]
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis_names), P(axis_names)), out_specs=P(axis_names),
-             check_rep=False)
+             check_vma=False)
     def _ar(q, s):
         acc = jax.lax.psum(q.astype(jnp.int32) * 1, axis_name=axis_names)
         s_max = jax.lax.pmax(s, axis_name=axis_names)

@@ -60,7 +60,7 @@ def test_activation_spec_context_applies_constraint():
 def test_cache_layout_seq_spec():
     from jax.sharding import AbstractMesh
     from repro.distributed import cache_specs
-    mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cache = jax.ShapeDtypeStruct((32, 128, 32768, 8, 128), jnp.bfloat16)
     spec = jax.tree.leaves(
         cache_specs(cache, mesh, ShardingPlan(cache_layout="seq")),

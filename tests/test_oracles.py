@@ -31,6 +31,15 @@ def test_compare_antisymmetric():
             assert o.compare(a, b, "c") == -o.compare(b, a, "c")
 
 
+def test_compare_far_apart_latents():
+    """A latent gap past exp's range decides the compare for the larger
+    key (92 vs -22 at the reasoning profile's temperature overflowed)."""
+    keys = as_keys(["k0", "k1"], [92.0, -22.0])
+    o = SimulatedOracle(REASONING)
+    assert o.compare(keys[0], keys[1], "c") == 1
+    assert o.compare(keys[1], keys[0], "c") == -1
+
+
 def test_factual_profile_scores_accurately():
     keys = mk(30, seed=2)
     o = SimulatedOracle(FACTUAL)

@@ -16,7 +16,7 @@ from repro.models import LM
 def fake_mesh_16x16():
     """AbstractMesh stands in for the production mesh (no devices needed)."""
     from jax.sharding import AbstractMesh
-    return AbstractMesh((("data", 16), ("model", 16)))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", list_archs())
