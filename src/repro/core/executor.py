@@ -42,6 +42,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Optional, Sequence
 
+from ..trace import span, traced
 from .oracles.base import CallRecord, LedgerView
 from .types import InvalidOutputError, SortResult, SortSpec
 
@@ -324,6 +325,7 @@ class ProbePlanExecutor:
                 and type(ps) in _DEFERRED_KIND
                 and hasattr(run.ordering.oracle, "begin_probe_round"))
 
+    @traced("executor.tick")
     def tick(self) -> bool:
         """One scheduling tick; returns True while any plan remains live."""
         live = []
@@ -331,7 +333,8 @@ class ProbePlanExecutor:
             if run.done:
                 continue
             if not run.primed:
-                run._advance(None)
+                with span("executor.advance", plan=run.name):
+                    run._advance(None)
             if not run.done:
                 live.append(run)
         live = self._enforce_ledger_budgets(live)
@@ -383,7 +386,8 @@ class ProbePlanExecutor:
                     # the token carries those records for exact per-plan
                     # attribution (drafts landed at begin time above)
                     run.records.extend(getattr(token, "extra_records", ()))
-                    ready.append((run, _fold_raw(run.ordering, ps, raw)))
+                    with span("executor.advance", plan=run.name):
+                        ready.append((run, _fold_raw(run.ordering, ps, raw)))
             finally:
                 for run, _ps, token in pending:
                     try:
@@ -393,11 +397,13 @@ class ProbePlanExecutor:
                         pass  # best-effort drain on the error path
                     run.records.extend(getattr(token, "extra_records", ()))
         for run, value in ready:
-            run._advance(value)
+            with span("executor.advance", plan=run.name):
+                run._advance(value)
         if self.prefetch:
             self._prefetch_next_rounds()
         return any(not r.done for r in self.runs)
 
+    @traced("executor.prefetch")
     def _prefetch_next_rounds(self) -> None:
         """Peek every live plan's NEXT pending probe set and enqueue
         prefix fills for the regions it will share, so the warm-ups ride

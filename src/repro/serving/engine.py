@@ -61,7 +61,6 @@ from __future__ import annotations
 import itertools
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Optional, Sequence, Union
 
 import jax
@@ -74,6 +73,7 @@ from ..distributed.context import shard_context
 from ..distributed.sharding import (ShardingPlan, data_axes, named,
                                     param_specs, rows_spec)
 from ..models.model import LM
+from ..trace import span, traced
 from .kv_pool import KVBlockPool, PoolExhausted
 from .locality import plan_window_jobs
 
@@ -131,6 +131,9 @@ def read_yes_no(logits) -> bool:
 @dataclass
 class ServeStats:
     prefill_tokens: int = 0
+    # the non-PAD tokens among ``prefill_tokens``: the padding a submission
+    # computes is their difference
+    prefill_live_tokens: int = 0
     decode_tokens: int = 0
     # physical row-slots occupied across decode steps (padded batch rows per
     # step, whether or not the row produced a useful token).  Lockstep holds
@@ -314,12 +317,19 @@ class ServeEngine:
         self._paged_ids = itertools.count()
         self.stats = ServeStats()
         if mesh is None:
-            self._prefill = jax.jit(partial(lm.prefill,
-                                            reserve=max_new_tokens))
-            self._decode = jax.jit(lm.decode_step)
+            # named functions, not partials, so a profile names every
+            # program (jax.jit calls a partial "_unknown")
+            def prefill(params, batch):
+                return lm.prefill(params, batch, reserve=max_new_tokens)
+
             # prefix regions need exact-length caches (reserve=0) so the
             # suffix lands at the right absolute positions
-            self._prefill_exact = jax.jit(partial(lm.prefill, reserve=0))
+            def prefill_exact(params, batch):
+                return lm.prefill(params, batch, reserve=0)
+
+            self._prefill = jax.jit(prefill)
+            self._decode = jax.jit(lm.decode_step)
+            self._prefill_exact = jax.jit(prefill_exact)
             self._prefill_cont = jax.jit(lm.prefill_cont)
         else:
             # mesh-jitted closures: shard_context is read at TRACE time, so
@@ -384,9 +394,13 @@ class ServeEngine:
             # CPU carve-out paid a full arena copy per decode step)
             donate = (1,)
             if mesh is None:
-                self._decode_paged = jax.jit(
-                    partial(lm.decode_step_paged, block_size=block_size),
-                    donate_argnums=donate)
+                def decode_paged(params, arenas, tokens, positions, tables):
+                    return lm.decode_step_paged(params, arenas, tokens,
+                                                positions, tables,
+                                                block_size=block_size)
+
+                self._decode_paged = jax.jit(decode_paged,
+                                             donate_argnums=donate)
             else:
                 arena_shardings = self.pool.arena_shardings
                 daxes = self._daxes if dp_probe_slices else ()
@@ -407,11 +421,17 @@ class ServeEngine:
                 self._decode_paged = jax.jit(_decode_paged_sharded,
                                              donate_argnums=donate)
             if paged_kernel:
+                def decode_paged_kernel(params, arenas, tokens, positions,
+                                        tables):
+                    return lm.decode_step_paged(params, arenas, tokens,
+                                                positions, tables,
+                                                block_size=block_size,
+                                                impl="kernel")
+
                 # "check" must NOT donate the arena into the kernel call —
                 # the dense source-of-truth call consumes it right after
                 self._decode_paged_kernel = jax.jit(
-                    partial(lm.decode_step_paged, block_size=block_size,
-                            impl="kernel"),
+                    decode_paged_kernel,
                     donate_argnums=(() if paged_kernel == "check"
                                     else donate))
         self._embed_cache: dict = {}
@@ -486,6 +506,21 @@ class ServeEngine:
             batch = {"enc_embeds": emb, "tokens": toks}
         return batch
 
+    def _count_prefill(self, tokens: np.ndarray) -> None:
+        """Count a prefill array's tokens: all of them, and the non-PAD
+        ones."""
+        self.stats.prefill_tokens += int(tokens.size)
+        self.stats.prefill_live_tokens += int(np.count_nonzero(tokens != PAD))
+
+    @staticmethod
+    def _read_back(logits, out: np.ndarray, idx: Sequence[int]) -> None:
+        """Wait for a submission's float32 logits, then copy the rows of
+        its live prompts to ``out`` at ``idx`` (bucket-dummy rows last)."""
+        with span("engine.wait"):
+            jax.block_until_ready(logits)
+        with span("engine.to_host"):
+            out[np.asarray(idx)] = np.asarray(logits)[:len(idx)]
+
     # --------------------------------------------------------------- probes
     @staticmethod
     def _region_key(pids: tuple, sids: Sequence[int], cls: int) -> tuple:
@@ -507,6 +542,7 @@ class ServeEngine:
             return None, prefix + suffix
         return prefix, suffix
 
+    @traced("engine.submit_probes")
     def submit_probes(self, prompts: Sequence[Prompt],
                       max_batch: Optional[int] = None) -> np.ndarray:
         """THE probe pathway: run a round of independent single-token probes
@@ -536,63 +572,71 @@ class ServeEngine:
         plain: dict[int, list[int]] = {}           # class -> indices
         structured: dict[int, list[tuple]] = {}    # class -> (idx, pids, sids)
         enc: list = [None] * n                     # per-index full token ids
-        for i, p in enumerate(prompts):
-            prefix, suffix = self._parts(p)
-            if prefix is not None and self.prefix_cache_enabled:
-                pids = tuple(self.tok.encode(prefix))
-                sids = self.tok.encode(suffix, bos=False)
-                enc[i] = list(pids) + sids
-                structured.setdefault(
-                    self._pad_class(len(enc[i])), []).append((i, pids, sids))
-            else:
-                enc[i] = self.tok.encode(suffix if prefix is None
-                                         else prefix + suffix)
-                plain.setdefault(self._pad_class(len(enc[i])), []).append(i)
+        # class -> (idx, region key, suffix length) of prefix-path rows
+        routed: dict[int, list[tuple]] = {}
+        with span("engine.encode"):
+            for i, p in enumerate(prompts):
+                prefix, suffix = self._parts(p)
+                if prefix is not None and self.prefix_cache_enabled:
+                    pids = tuple(self.tok.encode(prefix))
+                    sids = self.tok.encode(suffix, bos=False)
+                    enc[i] = list(pids) + sids
+                    structured.setdefault(self._pad_class(len(enc[i])),
+                                          []).append((i, pids, sids))
+                else:
+                    enc[i] = self.tok.encode(suffix if prefix is None
+                                             else prefix + suffix)
+                    plain.setdefault(self._pad_class(len(enc[i])),
+                                     []).append(i)
+
+            # Prefix-cache routing policy (per padded-length class): a row
+            # rides the prefix path only when its (prefix, start) entry is
+            # already cached or at least one class-mate shares it —
+            # otherwise the fill would cost as much as the monolithic row.
+            # Demoted rows join the class's plain submission; both pathways
+            # are bit-identical to monolithic prefill, so routing never
+            # changes results.
+            for cls in sorted(structured):
+                rows = structured[cls]
+                counts: dict[tuple, int] = {}
+                for _i, pids, sids in rows:
+                    key = self._region_key(pids, sids, cls)
+                    counts[key] = counts.get(key, 0) + 1
+                selected = []
+                for i, pids, sids in rows:
+                    key = self._region_key(pids, sids, cls)
+                    if key in self._prefix_lru or counts[key] >= 2:
+                        selected.append((i, key, len(sids)))
+                    else:
+                        plain.setdefault(cls, []).append(i)
+                if selected:
+                    routed[cls] = selected
         out = np.zeros((n, self.lm.cfg.vocab_size), np.float32)
 
-        # Prefix-cache routing policy (per padded-length class): a row rides
-        # the prefix path only when its (prefix, start) entry is already
-        # cached or at least one class-mate shares it — otherwise the fill
-        # would cost as much as the monolithic row.  Demoted rows join the
-        # class's plain submission; both pathways are bit-identical to
-        # monolithic prefill, so routing never changes results.
         window_jobs: list[tuple] = []              # (cls, lw, rows)
-        for cls in sorted(structured):
-            rows = structured[cls]
-            counts: dict[tuple, int] = {}
-            for _i, pids, sids in rows:
-                key = self._region_key(pids, sids, cls)
-                counts[key] = counts.get(key, 0) + 1
-            selected = []
-            for i, pids, sids in rows:
-                key = self._region_key(pids, sids, cls)
-                if key in self._prefix_lru or counts[key] >= 2:
-                    selected.append((i, key, len(sids)))
+        with span("engine.plan"):
+            for cls, selected in routed.items():
+                if self.locality:
+                    # GGR pass (serving/locality.py): region-clustered jobs
+                    # with per-group suffix windows, <= prefix_cache_size
+                    # regions per job, cold jobs before warm jobs
+                    jobs = plan_window_jobs(selected,
+                                            lru_keys=self._prefix_lru.keys(),
+                                            cache_size=self.prefix_cache_size,
+                                            bucket=self.bucket_shapes)
                 else:
-                    plain.setdefault(cls, []).append(i)
-            if not selected:
-                continue
-            if self.locality:
-                # GGR pass (serving/locality.py): region-clustered jobs
-                # with per-group suffix windows, <= prefix_cache_size
-                # regions per job, cold jobs before warm jobs
-                jobs = plan_window_jobs(selected,
-                                        lru_keys=self._prefix_lru.keys(),
-                                        cache_size=self.prefix_cache_size,
-                                        bucket=self.bucket_shapes)
-            else:
-                # reactive baseline: one class-global window sized by the
-                # round's worst row; rows shorter than lw recompute a few
-                # of their own prefix-tail tokens, which is bit-identical
-                # (causal KV slicing is exact at any split)
-                lw = max(s for _, _, s in selected)
-                lw = _next_pow2(max(lw, 8)) if self.bucket_shapes else lw
-                jobs = [(lw, [(i, key) for i, key, _ in selected])]
-            for lw, sel in jobs:
-                if lw >= cls:                      # no cached span left
-                    plain.setdefault(cls, []).extend(i for i, _ in sel)
-                    continue
-                window_jobs.append((cls, lw, sel))
+                    # reactive baseline: one class-global window sized by
+                    # the round's worst row; rows shorter than lw recompute
+                    # a few of their own prefix-tail tokens, which is
+                    # bit-identical (causal KV slicing is exact at any split)
+                    lw = max(s for _, _, s in selected)
+                    lw = _next_pow2(max(lw, 8)) if self.bucket_shapes else lw
+                    jobs = [(lw, [(i, key) for i, key, _ in selected])]
+                for lw, sel in jobs:
+                    if lw >= cls:                  # no cached span left
+                        plain.setdefault(cls, []).extend(i for i, _ in sel)
+                        continue
+                    window_jobs.append((cls, lw, sel))
 
         def chunked(idx):
             # max_batch None here means the engine was built with
@@ -603,15 +647,19 @@ class ServeEngine:
             for g in chunked(sorted(plain[cls])):
                 lease = self._lease_probe_blocks(len(g), cls)
                 try:
-                    tokens = self._pad_ids([enc[i] for i in g], maxlen=cls)
-                    logits, _ = self._prefill(self.params,
-                                              self._make_batch(tokens))
-                    self.stats.prefill_tokens += int(tokens.size)
+                    with span("engine.pad"):
+                        tokens = self._pad_ids([enc[i] for i in g],
+                                               maxlen=cls)
+                        batch = self._make_batch(tokens)
+                    with span("engine.dispatch", kind="prefill",
+                              rows=tokens.shape[0], length=cls, cached=0):
+                        logits, _ = self._prefill(self.params, batch)
+                        logits = logits.astype(jnp.float32)
+                    self._count_prefill(tokens)
                     self.stats.calls += 1
                     self.stats.probe_rows += len(g)
                     self.stats.probe_row_slots += int(tokens.shape[0])
-                    out[np.asarray(g)] = np.asarray(
-                        logits.astype(jnp.float32))[:len(g)]  # drop pad rows
+                    self._read_back(logits, out, g)
                 finally:
                     self._release_lease(lease)
         for cls, lw, selected in window_jobs:
@@ -627,13 +675,11 @@ class ServeEngine:
                     idx = [i for i, _ in g]
                     lease = self._lease_probe_blocks(len(g), cls)
                     try:
-                        logits = self._run_window(cls, lw,
-                                                  [enc[i] for i in idx],
-                                                  [key for _, key in g],
-                                                  dense)
+                        self._run_window(cls, lw, [enc[i] for i in idx],
+                                         [key for _, key in g], dense,
+                                         out, idx)
                     finally:
                         self._release_lease(lease)
-                    out[np.asarray(idx)] = logits
             finally:
                 self._release_pins(pins)
         return out
@@ -737,33 +783,43 @@ class ServeEngine:
             pending = by_len[region_len]
             for batch in (pending[i:i + step]
                           for i in range(0, len(pending), step)):
-                self.stats.prefix_misses += len(batch)
-                self.stats.prefix_fill_submissions += 1
-                rows_p = (_next_pow2(len(batch)) if self.bucket_shapes
-                          else len(batch))
-                arr = np.full((rows_p, region_len), PAD, np.int32)
-                for r, (pids, pad) in enumerate(batch):
-                    arr[r, pad:] = pids
-                _, caches = self._prefill_exact(self.params,
-                                               self._make_batch(arr))
-                self.stats.prefill_tokens += int(arr.size)
-                self.stats.prefix_tokens_saved -= int(arr.size)
-                row_blocks = self._pool_rows(len(batch), region_len)
-                if row_blocks is not None:
-                    self.pool.write(caches, row_blocks)
-                for r, key in enumerate(batch):
-                    if row_blocks is not None:
-                        entry = PrefixEntry(region_len, blocks=row_blocks[r])
-                    else:
-                        entry = PrefixEntry(region_len, caches=jax.tree.map(
-                            lambda l, r=r: l if l.ndim == 2 else l[:, r:r + 1],
-                            caches))
-                    self._prefix_lru[key] = entry
-                    refs[key] = entry
-                    pin(entry)
-                while len(self._prefix_lru) > self.prefix_cache_size:
-                    self._evict_one_prefix()
+                self._fill_batch(region_len, batch, refs, pin)
         return refs, pins
+
+    @traced("engine.fill")
+    def _fill_batch(self, region_len: int, batch: list, refs: dict,
+                    pin) -> None:
+        """One fill submission of :meth:`_fill_prefix_entries`: prefill
+        the regions ``batch`` of one length, store each as an LRU entry in
+        ``refs`` and ``pin`` it."""
+        self.stats.prefix_misses += len(batch)
+        self.stats.prefix_fill_submissions += 1
+        rows_p = _next_pow2(len(batch)) if self.bucket_shapes else len(batch)
+        with span("engine.pad"):
+            arr = np.full((rows_p, region_len), PAD, np.int32)
+            for r, (pids, pad) in enumerate(batch):
+                arr[r, pad:] = pids
+            fed = self._make_batch(arr)
+        with span("engine.dispatch", kind="prefill_exact", rows=rows_p,
+                  length=region_len, cached=0):
+            _, caches = self._prefill_exact(self.params, fed)
+        self._count_prefill(arr)
+        self.stats.prefix_tokens_saved -= int(arr.size)
+        row_blocks = self._pool_rows(len(batch), region_len)
+        if row_blocks is not None:
+            self.pool.write(caches, row_blocks)
+        for r, key in enumerate(batch):
+            if row_blocks is not None:
+                entry = PrefixEntry(region_len, blocks=row_blocks[r])
+            else:
+                entry = PrefixEntry(region_len, caches=jax.tree.map(
+                    lambda l, r=r: l if l.ndim == 2 else l[:, r:r + 1],
+                    caches))
+            self._prefix_lru[key] = entry
+            refs[key] = entry
+            pin(entry)
+        while len(self._prefix_lru) > self.prefix_cache_size:
+            self._evict_one_prefix()
 
     def _pool_rows(self, rows: int, length: int) -> Optional[list]:
         """Allocate a block run per row (evicting cold prefix entries if
@@ -801,55 +857,65 @@ class ServeEngine:
         bits, so both storage schemes execute identically)."""
         if entry.caches is not None:
             return entry.caches
-        return self.pool.gather_stacked(entry.blocks, entry.length)
+        with span("engine.gather"):
+            return self.pool.gather_stacked(entry.blocks, entry.length)
 
     def _run_window(self, cls: int, lw: int, full_ids: list,
-                    keys: list, dense: dict) -> np.ndarray:
+                    keys: list, dense: dict, out: np.ndarray,
+                    idx: list) -> None:
         """One suffix-window submission: every row attends over its own
         cached-KV slice [0, cls - lw) (selected per row from the window
         job's ``dense`` materialized entries) plus the recomputed window
         tokens [cls - lw, cls).  Bit-identical to a monolithic padded
-        prefill of the full rows."""
+        prefill of the full rows; the rows' logits land in ``out`` at
+        ``idx``."""
         r_star = cls - lw
-        uniq: list = []
-        uniq_of: dict[tuple, int] = {}
-        for key in keys:
-            if key not in uniq_of:
-                uniq_of[key] = len(uniq)
-                uniq.append(dense[key])
         rows = len(full_ids)
         rows_p = _next_pow2(rows) if self.bucket_shapes else rows
-        arr = np.full((rows_p, lw), PAD, np.int32)
-        for r, ids in enumerate(full_ids):
-            row = [PAD] * (cls - len(ids)) + list(ids)  # left-padded full row
-            arr[r] = row[r_star:]
-        eidx = np.zeros((rows_p,), np.int32)
-        eidx[:rows] = [uniq_of[k] for k in keys]   # dummy rows reuse entry 0
+        with span("engine.assemble"):
+            uniq: list = []
+            uniq_of: dict[tuple, int] = {}
+            for key in keys:
+                if key not in uniq_of:
+                    uniq_of[key] = len(uniq)
+                    uniq.append(dense[key])
+            eidx = np.zeros((rows_p,), np.int32)
+            eidx[:rows] = [uniq_of[k] for k in keys]  # dummy rows: entry 0
 
-        def cat(*leaves):
-            if leaves[0].ndim == 2:                # stacked pos: arange(R)
-                return leaves[0][:, :r_star]
-            return jnp.concatenate([l[:, :, :r_star] for l in leaves], axis=1)
+            def cat(*leaves):
+                if leaves[0].ndim == 2:            # stacked pos: arange(R)
+                    return leaves[0][:, :r_star]
+                return jnp.concatenate([l[:, :, :r_star] for l in leaves],
+                                       axis=1)
 
-        assembled = jax.tree.map(cat, *uniq)
-        idx = jnp.asarray(eidx)
-        # mesh serving: the per-row cache gather is committed to the same
-        # row split as the token batch (_put_rows axis=1 — caches carry the
-        # row dim second), so a sliced submission's shards hold only their
-        # rows' prefix KV; shared pos leaves (ndim 2) stay replicated
-        assembled = jax.tree.map(
-            lambda l: l if l.ndim == 2 else self._put_rows(
-                jnp.take(l, idx, axis=1), axis=1),
-            assembled)
-        logits, _ = self._prefill_cont(self.params, assembled,
-                                       self._make_batch(arr))
-        self.stats.prefill_tokens += int(arr.size)
+            assembled = jax.tree.map(cat, *uniq)
+            rows_of = jnp.asarray(eidx)
+            # mesh serving: the per-row cache gather is committed to the
+            # same row split as the token batch (_put_rows axis=1 — caches
+            # carry the row dim second), so a sliced submission's shards
+            # hold only their rows' prefix KV; shared pos leaves (ndim 2)
+            # stay replicated
+            assembled = jax.tree.map(
+                lambda l: l if l.ndim == 2 else self._put_rows(
+                    jnp.take(l, rows_of, axis=1), axis=1),
+                assembled)
+        with span("engine.pad"):
+            arr = np.full((rows_p, lw), PAD, np.int32)
+            for r, ids in enumerate(full_ids):
+                row = [PAD] * (cls - len(ids)) + list(ids)  # left-padded
+                arr[r] = row[r_star:]
+            batch = self._make_batch(arr)
+        with span("engine.dispatch", kind="prefill_cont", rows=rows_p,
+                  length=lw, cached=r_star):
+            logits, _ = self._prefill_cont(self.params, assembled, batch)
+            logits = logits.astype(jnp.float32)
+        self._count_prefill(arr)
         self.stats.calls += 1
         self.stats.probe_rows += rows
         self.stats.probe_row_slots += rows_p
         # monolithic baseline: cls tokens per padded row of this submission
         self.stats.prefix_tokens_saved += rows_p * cls - int(arr.size)
-        return np.asarray(logits.astype(jnp.float32))[:rows]
+        self._read_back(logits, out, idx)
 
     def last_logits(self, prompts: Sequence[Prompt]) -> np.ndarray:
         return self.submit_probes(prompts)
@@ -966,8 +1032,11 @@ class ServeEngine:
             limits = np.minimum(np.asarray(max_new_per, np.int64), self.max_new)
         limits = np.concatenate([limits, np.zeros((b - n,), np.int64)])
         horizon = int(limits.max(initial=0))
-        logits, caches = self._prefill(self.params, self._make_batch(tokens))
-        self.stats.prefill_tokens += int(tokens.size)
+        batch = self._make_batch(tokens)
+        with span("engine.dispatch", kind="prefill", rows=b, length=s,
+                  cached=0):
+            logits, caches = self._prefill(self.params, batch)
+        self._count_prefill(tokens)
         self.stats.calls += 1
         out = np.full((b, horizon), EOS, np.int64)  # unwritten tail decodes empty
         cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
@@ -1122,9 +1191,11 @@ class ServeEngine:
     def _admit_plain(self, cls: int, group: list) -> None:
         """Monolithic prefill of same-class rows into their block runs."""
         tokens = self._pad_ids([enc for _, enc, *_ in group], maxlen=cls)
-        logits, caches = self._prefill_exact(self.params,
-                                             self._make_batch(tokens))
-        self.stats.prefill_tokens += int(tokens.size)
+        batch = self._make_batch(tokens)
+        with span("engine.dispatch", kind="prefill_exact",
+                  rows=tokens.shape[0], length=cls, cached=0):
+            logits, caches = self._prefill_exact(self.params, batch)
+        self._count_prefill(tokens)
         self.stats.calls += 1
         row_blocks = self._alloc_rows(
             [self.pool.blocks_for(cls + limit)
@@ -1185,9 +1256,12 @@ class ServeEngine:
         assembled = jax.tree.map(
             lambda l: l[:, :start] if l.ndim == 2 else l[:, :, :start],
             self._entry_caches(entry))
-        logits, caches = self._prefill_cont(self.params, assembled,
-                                            self._make_batch(arr))
-        self.stats.prefill_tokens += int(arr.size)
+        batch = self._make_batch(arr)
+        with span("engine.dispatch", kind="prefill_cont", rows=rows_p,
+                  length=w, cached=start):
+            logits, caches = self._prefill_cont(self.params, assembled,
+                                                batch)
+        self._count_prefill(arr)
         self.stats.calls += 1
         self.stats.prefix_tokens_saved += rows_p * cls - int(arr.size)
         shared_run = list(entry.blocks[:n_shared])
@@ -1207,6 +1281,7 @@ class ServeEngine:
                 rid=rid, cls=cls, limit=limit, blocks=row_blocks[r],
                 n_shared=n_shared, cur=int(first[r]))
 
+    @traced("engine.paged_step")
     def paged_step(self) -> dict[int, str]:
         """One continuous-batching decode step: record each active row's
         pending token, retire rows that just finished (freeing their blocks

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.layers import KVCache, PagedKV, dtype_of
+from ..trace import traced
 
 
 class PoolExhausted(RuntimeError):
@@ -185,6 +186,7 @@ class KVBlockPool:
         self.total_unstashed += len(ids)
 
     # ------------------------------------------------------ device arenas
+    @traced("pool.write")
     def write(self, stack_caches, row_blocks: Sequence[Sequence[int]],
               start: int = 0) -> None:
         """Scatter prefill-computed KV into block runs: positions

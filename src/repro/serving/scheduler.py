@@ -68,6 +68,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from ..trace import span, traced
 from .engine import ServeEngine
 from .kv_pool import PoolExhausted
 
@@ -267,7 +268,6 @@ class BatchScheduler:
         self.probes_drafted = 0    # cascade wave-1 rows served by the draft
         self.probes_escalated = 0  # cascade rows re-run on the large engine
         self.fills_serviced = 0    # PrefixFill work items serviced
-        self.regions_prefetched = 0   # prefix regions ensured resident
         self.steps = 0             # unified steps taken (decode or probe-only)
         self._rid_of_engine: dict[int, Request] = {}
         # outputs finished by step() and not yet claimed by a driver
@@ -406,47 +406,49 @@ class BatchScheduler:
         assert self.paged, "step() requires a paged-capable engine"
         eng = self.engine
         self.steps += 1
-        # -- 1. decode admission (probe and fill items never block it —
-        # they hold no persistent capacity)
-        decode_items = []
-        rest: list = []
-        for w in self.work:
-            (decode_items if isinstance(w, Request) else rest).append(w)
-        try:
-            if decode_items:
-                self._admit_decode(decode_items)
-        finally:
-            # reassign even when admission raises mid-wave: admitted items
-            # were removed from decode_items in place (and failed
-            # resumes/preemptions reinserted), so the queue never holds a
-            # request that already owns an engine row
-            self.work = rest + decode_items   # unadmitted decode items wait
+        with span("scheduler.step", step=self.steps):
+            # -- 1. decode admission (probe and fill items never block it —
+            # they hold no persistent capacity)
+            decode_items = []
+            rest: list = []
+            for w in self.work:
+                (decode_items if isinstance(w, Request) else rest).append(w)
+            try:
+                if decode_items:
+                    self._admit_decode(decode_items)
+            finally:
+                # reassign even when admission raises mid-wave: admitted
+                # items were removed from decode_items in place (and failed
+                # resumes/preemptions reinserted), so the queue never holds
+                # a request that already owns an engine row
+                self.work = rest + decode_items   # unadmitted items wait
 
-        # -- 2. fills then probes ride the step gap
-        self._service_fills()
-        self._service_probes()
+            # -- 2. fills then probes ride the step gap
+            self._service_fills()
+            self._service_probes()
 
-        # serving-token billing: one token per ACTIVE owned row per decode
-        # step (suspended rows are parked, not billed — a preemption cycle
-        # bills exactly what a never-preempted run would)
-        for erid, req in self._rid_of_engine.items():
-            if erid in eng._paged_rows:
-                self._tstats(req.tenant).tokens_served += 1
+            # serving-token billing: one token per ACTIVE owned row per
+            # decode step (suspended rows are parked, not billed — a
+            # preemption cycle bills exactly what a never-preempted run
+            # would)
+            for erid, req in self._rid_of_engine.items():
+                if erid in eng._paged_rows:
+                    self._tstats(req.tenant).tokens_served += 1
 
-        # -- 3. one decode step (a no-op when no rows are active, so a
-        # probe storm burns probe submissions, never decode progress)
-        finished: dict[int, str] = {}
-        for erid, text in eng.paged_step().items():
-            req = self._rid_of_engine.pop(erid, None)
-            if req is None:               # a concurrent driver's row — e.g.
-                eng._paged_finished[erid] = text   # a nested generate
-                continue
-            req.output = text
-            self.completed[req.rid] = req
-            self._fresh[req.rid] = text
-            finished[req.rid] = text
-            self._tstats(req.tenant).finished += 1
-        return finished
+            # -- 3. one decode step (a no-op when no rows are active, so a
+            # probe storm burns probe submissions, never decode progress)
+            finished: dict[int, str] = {}
+            for erid, text in eng.paged_step().items():
+                req = self._rid_of_engine.pop(erid, None)
+                if req is None:           # a row this queue does not own,
+                    eng._paged_finished[erid] = text   # a nested generate's
+                    continue
+                req.output = text
+                self.completed[req.rid] = req
+                self._fresh[req.rid] = text
+                finished[req.rid] = text
+                self._tstats(req.tenant).finished += 1
+            return finished
 
     # ------------------------------------------------- weighted admission
     def _need(self, w: Request) -> int:
@@ -633,8 +635,9 @@ class BatchScheduler:
         if self.paged:
             self.step()
         else:
-            self._service_fills()
-            self.probe_results.update(self.run_probes())
+            with span("scheduler.step"):      # lockstep counts no steps
+                self._service_fills()
+                self.probe_results.update(self.run_probes())
         return self.work_remaining
 
     def resolve(self, future: RoundFuture) -> RoundFuture:
@@ -805,6 +808,7 @@ class BatchScheduler:
             return mb
         return -(-mb // shards) * shards
 
+    @traced("scheduler.probes")
     def _service_probe_items(self, pending: list) -> dict[int, np.ndarray]:
         """Run one merged probe submission over ``pending`` (already
         removed from the queue).
@@ -929,6 +933,7 @@ class BatchScheduler:
         self.probes_escalated += len(escalated)
         return escalated
 
+    @traced("scheduler.fills")
     def _service_fills(self) -> None:
         fills = [w for w in self.work if isinstance(w, PrefixFill)]
         if not fills:
@@ -938,9 +943,8 @@ class BatchScheduler:
         if not prompts:
             return
         try:
-            n = self.engine.prefetch_prefixes(prompts)
+            self.engine.prefetch_prefixes(prompts)
         except BaseException:
             self.work[0:0] = fills        # transient failure: keep the work
             raise
         self.fills_serviced += len(fills)
-        self.regions_prefetched += n

@@ -1,5 +1,7 @@
 """The launcher's shared constructors, its one-chip memory reckoning, and the
 compile-cache placement rule (``repro.launch``)."""
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -53,3 +55,25 @@ def test_constructors_serve_a_probe_round():
     assert sized.pool.num_blocks == FULL_WIDTH_ENGINE["pool_blocks"]
     scores = sized.score([f"item {i}" for i in range(4)], "relevance")
     assert len(scores) == 4 and np.isfinite(scores).all()
+
+
+def test_engine_programs_are_named():
+    """Every program the engine jits has a name of its own, so the XLA
+    module lines of a profile name each one (jax.jit calls a partial
+    "_unknown")."""
+    lm, params = build_lm("stablelm-1.6b", full=False)
+    engine = build_engine(lm, params, full=False, **FULL_WIDTH_ENGINE)
+    batch = engine._make_batch(np.zeros((8, 16), np.int32))
+    rows = np.zeros((8,), np.int32)
+    lowered = {
+        "_prefill": engine._prefill.lower(params, batch),
+        "_prefill_exact": engine._prefill_exact.lower(params, batch),
+        "_decode_paged": engine._decode_paged.lower(
+            params, engine.pool.arenas, rows[:, None], rows,
+            np.zeros((8, 2), np.int32)),
+    }
+    names = {k: re.search(r"module @(\w+)", v.as_text()).group(1)
+             for k, v in lowered.items()}
+    assert names == {"_prefill": "jit_prefill",
+                     "_prefill_exact": "jit_prefill_exact",
+                     "_decode_paged": "jit_decode_paged"}

@@ -28,6 +28,28 @@ def test_generate_shapes_and_stats(engine):
     assert engine.stats.calls >= 1
 
 
+def test_prefill_live_tokens_count_the_prompts(engine):
+    """``prefill_live_tokens`` counts the non-PAD tokens of every prefill
+    array: exactly the prompts' own tokens on the plain probe path and in
+    paged admission, and never more than ``prefill_tokens``."""
+    prompts = ["hello world", "rank me", "a somewhat longer prompt here"]
+    own = sum(len(engine.tok.encode(p)) for p in prompts)
+    for run in (lambda: engine.submit_probes(prompts),
+                lambda: engine.generate(prompts, max_new=1)):
+        live0 = engine.stats.prefill_live_tokens
+        all0 = engine.stats.prefill_tokens
+        run()
+        live = engine.stats.prefill_live_tokens - live0
+        assert live == own
+        assert live < engine.stats.prefill_tokens - all0
+    # structured rows: region fills and suffix windows
+    live0 = engine.stats.prefill_live_tokens
+    all0 = engine.stats.prefill_tokens
+    engine.score([f"thing {i}" for i in range(4)], "freshness")
+    live = engine.stats.prefill_live_tokens - live0
+    assert 0 < live <= engine.stats.prefill_tokens - all0
+
+
 def test_score_deterministic(engine):
     s1 = engine.score(["aaa", "bbb", "ccc"], "positivity")
     s2 = engine.score(["aaa", "bbb", "ccc"], "positivity")
