@@ -20,15 +20,18 @@ Block 0 is a permanent dummy: padded block-table slots and bucket-dummy
 rows point (and may write) there, and it is never allocated, so its garbage
 is only ever read through a NEG_INF mask.  Allocation/refcounts are plain
 Python/numpy (the scheduler is host-side anyway); only the arenas live on
-device, updated functionally by the jitted decode step and the eager
-scatter/gather helpers here.  See DESIGN.md "Paged KV pool".
+device, updated in place by the jitted decode step and the jitted block
+writer here (both donate the arena), and read by the jitted block reader.
+See DESIGN.md "Paged KV pool".
 """
 from __future__ import annotations
 
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..models.layers import KVCache, PagedKV, dtype_of
 from ..trace import traced
@@ -37,6 +40,132 @@ from ..trace import traced
 class PoolExhausted(RuntimeError):
     """Raised when an allocation cannot be satisfied even after the caller
     has evicted everything it is willing to evict."""
+
+
+def _to_blocks(leaf, rows: int, start: int, nb: int, bs: int):
+    """The (n, rows*nb, bs, KV, hd) block slab of positions ``[start, S)``
+    of the first ``rows`` rows of a (n, B, S, KV, hd) prefill leaf, the
+    partial last block zero-padded."""
+    n, _, s = leaf.shape[:3]
+    leaf = leaf[:, :rows, start:]
+    pad = nb * bs - (s - start)
+    if pad:
+        leaf = jnp.pad(leaf, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+    return leaf.reshape(n, rows * nb, bs, *leaf.shape[3:])
+
+
+def _put_slices(dst, src, ids):
+    """Block ``i`` of ``src`` into block ``ids[i]`` of ``dst``, one
+    dynamic-update-slice each: in place when a block is a contiguous run of
+    the arena's memory."""
+    def put(i, dst):
+        return lax.dynamic_update_slice_in_dim(
+            dst, lax.dynamic_slice_in_dim(src, i, 1, axis=1), ids[i], axis=1)
+
+    return lax.fori_loop(0, ids.shape[0], put, dst)
+
+
+def _put_lanes(dst, src, ids):
+    """The same write when the block axis is innermost in memory (the TPU's
+    compact layout of an arena whose head size is under a lane tile, as
+    stablelm-1.6b's 64): a block is then one lane of every tile, so a write
+    per block would touch the whole arena once for each block.  Instead one
+    pass places every block: the stored bits, split into bytes (small
+    integers that bf16 holds exactly), go through a one-hot (2m, NB) matmul
+    weighted 1 and 256 that the MXU accumulates in f32 without rounding, and
+    the reassembled bits replace the written lanes — the same bits as a
+    copy, -0.0 and NaN included."""
+    nbytes = dst.dtype.itemsize
+    uint = jnp.dtype(f"uint{8 * nbytes}")
+    onehot = ids[:, None] == jnp.arange(dst.shape[1])[None, :]
+    # one relayout of the slab to the arena's lane order, before the byte
+    # planes are cut from it (else each plane is relayouted on its own)
+    src = lax.optimization_barrier(jnp.moveaxis(src, 1, -1))
+    bits = lax.bitcast_convert_type(src, uint)
+    placed = None
+    for lo in range(0, nbytes, 2):      # 16 bits a matmul: sums < 2**24
+        hi = min(lo + 2, nbytes)
+        planes = jnp.concatenate(
+            [(bits >> (8 * b)) & 0xFF for b in range(lo, hi)], axis=-1)
+        weights = jnp.concatenate(
+            [onehot * float(256 ** (b - lo)) for b in range(lo, hi)])
+        part = jnp.dot(planes.astype(jnp.bfloat16),
+                       weights.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+        part = part.astype(uint) << (8 * lo)
+        placed = part if placed is None else placed | part
+    vals = lax.bitcast_convert_type(placed, dst.dtype)
+    out = jnp.where(onehot.any(0), vals, jnp.moveaxis(dst, 1, -1))
+    return jnp.moveaxis(out, -1, 1)
+
+
+def _take_slices(src, ids):
+    return jnp.take(src, ids, axis=1)
+
+
+def _take_lanes(src, ids):
+    """Blocks ``ids`` of an arena leaf whose block axis is innermost in
+    memory, without the relayout copy of the whole leaf that a gather along
+    that axis makes there: each byte of the stored bits goes through a
+    one-hot (NB, m) matmul, exact as in :func:`_put_lanes`, reading the leaf
+    once a byte and never writing it."""
+    nbytes = src.dtype.itemsize
+    uint = jnp.dtype(f"uint{8 * nbytes}")
+    onehot = jnp.arange(src.shape[1])[:, None] == ids[None, :]
+    bits = lax.bitcast_convert_type(jnp.moveaxis(src, 1, -1), uint)
+    out = None
+    for b in range(nbytes):
+        plane = ((bits >> (8 * b)) & 0xFF).astype(jnp.bfloat16)
+        part = jnp.dot(plane, onehot.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+        part = part.astype(uint) << (8 * b)
+        out = part if out is None else out | part
+    return jnp.moveaxis(lax.bitcast_convert_type(out, src.dtype), -1, 1)
+
+
+def _blocks_minor(leaf) -> bool:
+    """Whether the block axis of an arena leaf is innermost in memory."""
+    return leaf.format.layout.major_to_minor[-1] == 1
+
+
+def _block_writer(lanes: Sequence[bool], shardings=None):
+    """The pool's one write path, jitted with the arenas donated so the
+    result aliases their buffers: a write never copies the arena.
+
+    ``write_blocks(arenas, leaves, ids, start, nb)`` takes, per decoder
+    stack, the prefill's (k, v) leaves (n, B, S, KV, hd), forms the block
+    slab of positions ``[start, S)`` of the first ``len(ids) // nb`` rows
+    (bucket-dummy rows beyond them are dropped) and writes slab block ``i``
+    to arena block ``ids[i]``: by :func:`_put_lanes` for the stacks that
+    ``lanes`` marks (block axis innermost in memory), else by
+    :func:`_put_slices`.  Under a mesh the result is pinned to the arenas'
+    canonical ``shardings``, which donation needs to alias."""
+    def write_blocks(arenas, leaves, ids, start, nb):
+        rows = ids.shape[0] // nb
+        out = []
+        for arena, kv, by_lane in zip(arenas, leaves, lanes):
+            put = _put_lanes if by_lane else _put_slices
+            bs = arena.k.shape[2]
+            out.append(PagedKV(*(
+                put(dst, _to_blocks(leaf, rows, start, nb, bs), ids)
+                for dst, leaf in zip(arena, kv))))
+        if shardings is not None:
+            out = lax.with_sharding_constraint(out, shardings)
+        return out
+
+    return jax.jit(write_blocks, static_argnums=(3, 4), donate_argnums=0)
+
+
+def _block_reader(lanes: Sequence[bool]):
+    """The pool's one read path: ``read_blocks(arenas, ids)`` gives, per
+    decoder stack, the (n, len(ids), bs, KV, hd) K/V slabs of blocks
+    ``ids``, by :func:`_take_lanes` for the stacks that ``lanes`` marks."""
+    def read_blocks(arenas, ids):
+        return [PagedKV(*((_take_lanes if by_lane else _take_slices)(
+                    leaf, ids) for leaf in arena))
+                for arena, by_lane in zip(arenas, lanes)]
+
+    return jax.jit(read_blocks)
 
 
 class KVBlockPool:
@@ -58,16 +187,18 @@ class KVBlockPool:
         # arrays in the FEATURE layout — kv-heads over `model`, block dim
         # replicated — so everything below this line (free list, refcounts,
         # stashes) is mesh-oblivious: a block id addresses the same arena
-        # slice on every device.  ``_pin`` re-commits eager scatter/gather
-        # results to the canonical layout (a no-op when already there).
+        # slice on every device.  The block writer pins its result to the
+        # same layout, so it and the donated decode step alias in place.
         self.arena_shardings = None
         if mesh is not None:
-            import jax
             from ..distributed.sharding import (ShardingPlan, arena_specs,
                                                 named)
             self.arena_shardings = named(
                 mesh, arena_specs(self.arenas, mesh, plan or ShardingPlan()))
             self.arenas = jax.device_put(self.arenas, self.arena_shardings)
+        lanes = [_blocks_minor(a.k) for a in self.arenas]
+        self._write_blocks = _block_writer(lanes, self.arena_shardings)
+        self._read_blocks = _block_reader(lanes)
         # LIFO free list, block 0 (dummy) excluded for good
         self._free = list(range(num_blocks - 1, 0, -1))
         self._ref = np.zeros(num_blocks, np.int64)
@@ -83,17 +214,10 @@ class KVBlockPool:
         # blocks copied out to host stashes and scattered back
         self.total_stashed = 0
         self.total_unstashed = 0
-
-    def _pin(self, si: int, arena):
-        """Re-commit an eagerly-updated arena to the canonical sharding.
-        Eager scatter (`.at[ids].set`) lets XLA pick the result layout; a
-        device_put to the known NamedSharding is a no-op when it already
-        matches and a reshard otherwise, so the donated decode step always
-        sees identically-laid-out input."""
-        if self.arena_shardings is None:
-            return arena
-        import jax
-        return jax.device_put(arena, self.arena_shardings[si])
+        # every call of the block writer (prefill writes and unstashes) and
+        # the blocks it moved
+        self.total_writes = 0
+        self.total_written_blocks = 0
 
     # ---------------------------------------------------------- allocator
     @property
@@ -160,15 +284,14 @@ class KVBlockPool:
         block_size, KV, hd) K/V slabs as numpy arrays.  A stash is a plain
         value — it holds no pool references, so the caller decides when the
         source blocks are released."""
-        idx = jnp.asarray(np.asarray(list(ids), np.int32))
-        stash = [(np.asarray(jnp.take(a.k, idx, axis=1)),
-                  np.asarray(jnp.take(a.v, idx, axis=1)))
-                 for a in self.arenas]
+        slabs = self._read_blocks(self.arenas,
+                                  np.asarray(list(ids), np.int32))
+        stash = [(np.asarray(a.k), np.asarray(a.v)) for a in slabs]
         self.total_stashed += len(ids)
         return stash
 
     def unstash_blocks(self, stash: list, ids: Sequence[int]) -> None:
-        """Scatter a stash back into ``ids`` (the resume half): the blocks
+        """Write a stash back into ``ids`` (the resume half): the blocks
         need not be the ones stashed from — block contents are
         position-independent, the row's block TABLE carries the ordering —
         and a gather-out/scatter-back round trip is a copy of the stored
@@ -177,19 +300,17 @@ class KVBlockPool:
         ids = list(ids)
         assert stash and all(k.shape[1] == len(ids) for k, _ in stash), (
             "stash block count must match the destination run")
-        idx = jnp.asarray(np.asarray(ids, np.int32))
-        for si, (k, v) in enumerate(stash):
-            arena = self.arenas[si]
-            self.arenas[si] = self._pin(si, PagedKV(
-                k=arena.k.at[:, idx].set(jnp.asarray(k)),
-                v=arena.v.at[:, idx].set(jnp.asarray(v))))
+        # a stash slab (n, m, bs, KV, hd) is one row of m*bs positions
+        leaves = [[leaf.reshape(leaf.shape[0], 1, -1, *leaf.shape[3:])
+                   for leaf in kv] for kv in stash]
+        self._write(leaves, np.asarray(ids, np.int32), 0, len(ids))
         self.total_unstashed += len(ids)
 
     # ------------------------------------------------------ device arenas
     @traced("pool.write")
     def write(self, stack_caches, row_blocks: Sequence[Sequence[int]],
               start: int = 0) -> None:
-        """Scatter prefill-computed KV into block runs: positions
+        """Write prefill-computed KV into block runs: positions
         ``[start, S)`` of row ``r`` of ``stack_caches`` (a per-stack list of
         stacked :class:`KVCache`, leaves (n, B, S, KV, hd)) land in
         ``row_blocks[r]`` in order.  ``start`` must be block-aligned (a row
@@ -204,27 +325,20 @@ class KVBlockPool:
         nb = len(row_blocks[0])
         assert all(len(b) == nb for b in row_blocks), (
             "rows of one write must cover equal block counts")
-        ids = jnp.asarray(np.concatenate(
-            [np.asarray(b, np.int32) for b in row_blocks]))
-        rows = len(row_blocks)
-        for si, cache in enumerate(stack_caches):
-            k, v = cache.k, cache.v                  # (n, B, S, kv, hd)
-            n, _, s = k.shape[:3]
-            span = s - start
-            pad = nb * bs - span
-            assert pad >= 0, f"run of {nb} blocks < {span} positions"
+        for cache in stack_caches:
+            span = cache.k.shape[2] - start
+            assert nb * bs >= span, f"run of {nb} blocks < {span} positions"
+        ids = np.concatenate([np.asarray(b, np.int32) for b in row_blocks])
+        self._write([(c.k, c.v) for c in stack_caches], ids, start, nb)
 
-            def to_blocks(leaf):
-                leaf = leaf[:, :rows, start:]
-                if pad:
-                    leaf = jnp.pad(leaf, ((0, 0), (0, 0), (0, pad),
-                                          (0, 0), (0, 0)))
-                return leaf.reshape(n, rows * nb, bs, *leaf.shape[3:])
-
-            arena = self.arenas[si]
-            self.arenas[si] = self._pin(si, PagedKV(
-                k=arena.k.at[:, ids].set(to_blocks(k)),
-                v=arena.v.at[:, ids].set(to_blocks(v))))
+    def _write(self, leaves, ids: np.ndarray, start: int, nb: int) -> None:
+        """Run the block writer over every stack; the donated arenas are
+        replaced by its result, so no reference to them may outlive this."""
+        assert len(set(ids.tolist())) == len(ids), (
+            "a write's blocks must differ")
+        self.arenas = self._write_blocks(self.arenas, leaves, ids, start, nb)
+        self.total_writes += 1
+        self.total_written_blocks += len(ids)
 
     def gather_stacked(self, block_ids: Sequence[int], length: int):
         """Materialize a block run as the dense per-stack cache pytree the
@@ -232,17 +346,17 @@ class KVBlockPool:
         k/v (n, 1, length, KV, hd) and pos (n, length).  A gather is a copy
         of the stored bits, so downstream compute is bit-identical to
         holding the dense cache directly."""
-        ids = jnp.asarray(np.asarray(block_ids, np.int32))
+        slabs = self._read_blocks(self.arenas,
+                                  np.asarray(block_ids, np.int32))
         out = []
-        for arena in self.arenas:
-            n = arena.k.shape[0]
+        for slab in slabs:                       # (n, nb, bs, kv, hd)
+            n = slab.k.shape[0]
 
-            def dense(leaf):
-                g = jnp.take(leaf, ids, axis=1)      # (n, nb, bs, kv, hd)
+            def dense(g):
                 g = g.reshape(n, 1, -1, *g.shape[3:])
                 return g[:, :, :length]
 
             pos = jnp.broadcast_to(jnp.arange(length, dtype=jnp.int32),
                                    (n, length))
-            out.append(KVCache(dense(arena.k), dense(arena.v), pos))
+            out.append(KVCache(dense(slab.k), dense(slab.v), pos))
         return out

@@ -119,3 +119,62 @@ def test_full_width_dense_paged_decode_fits_one_chip(one_chip):
         _spec(one_chip, (DECODE_ROWS,), jnp.int32),
         _spec(one_chip, (DECODE_ROWS, MAXB), jnp.int32)).compile()
     assert _bytes(compiled) < V5E_HBM
+
+
+@pytest.mark.parametrize("name,rows,length,nb", [
+    ("stablelm-1.6b", 2, 154, 10), ("stablelm-1.6b", 8, 256, 16),
+    ("phi4-mini-3.8b", 2, 154, 10)])
+def test_pool_write_is_in_place_at_full_width(one_chip, name, rows, length,
+                                              nb):
+    """The pool's block writer at a full-width arena of 512 blocks, for
+    the placement the arena's default layout on the chip picks: block axis
+    innermost for stablelm-1.6b (head size 64, under a lane tile), so one
+    lane-placement pass; plain descending for phi4-mini-3.8b, so a slice a
+    block.  Either way the arena is aliased and no copy of it is made."""
+    import re
+
+    from repro.models.layers import PagedKV
+    from repro.serving.kv_pool import _block_writer
+    cfg = get_config(name)
+    shape = (cfg.n_layers, POOL, BLOCK, cfg.n_kv_heads, cfg.hd)
+    leaf = _spec(one_chip, shape, jnp.bfloat16)
+    default = jax.jit(lambda a: a).lower(leaf).compile()
+    lanes = default.input_formats[0][0].layout.major_to_minor[-1] == 1
+    assert lanes == (name == "stablelm-1.6b")
+    cache = _spec(one_chip, (cfg.n_layers, rows, length, cfg.n_kv_heads,
+                             cfg.hd), jnp.bfloat16)
+    compiled = _block_writer([lanes]).lower(
+        [PagedKV(k=leaf, v=leaf)], [(cache, cache)],
+        _spec(one_chip, (rows * nb,), jnp.int32), 0, nb).compile()
+    hlo = compiled.as_text()
+    assert ("input_output_alias={ {0}: (0, {}, may-alias), "
+            "{1}: (1, {}, may-alias) }") in hlo
+    ops = re.findall(re.escape(f"bf16[{','.join(map(str, shape))}]")
+                     + r"\{[^}]*\} ([\w-]+)\(", hlo)
+    assert not {"copy", "transpose", "scatter"} & set(ops), ops
+    leaf_bytes = 2 * int(jnp.prod(jnp.array(shape)))
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
+
+
+@pytest.mark.parametrize("name", ["stablelm-1.6b", "phi4-mini-3.8b"])
+def test_pool_read_makes_no_arena_copy_at_full_width(one_chip, name):
+    """The pool's block reader (prefix gathers, stashes) at a full-width
+    arena: no relayout copy of the arena, which an eager gather along a
+    block axis that is innermost in memory makes (stablelm-1.6b)."""
+    import re
+
+    from repro.models.layers import PagedKV
+    from repro.serving.kv_pool import _block_reader
+    cfg = get_config(name)
+    shape = (cfg.n_layers, POOL, BLOCK, cfg.n_kv_heads, cfg.hd)
+    leaf = _spec(one_chip, shape, jnp.bfloat16)
+    default = jax.jit(lambda a: a).lower(leaf).compile()
+    lanes = default.input_formats[0][0].layout.major_to_minor[-1] == 1
+    compiled = _block_reader([lanes]).lower(
+        [PagedKV(k=leaf, v=leaf)],
+        _spec(one_chip, (10,), jnp.int32)).compile()
+    ops = re.findall(re.escape(f"bf16[{','.join(map(str, shape))}]")
+                     + r"\{[^}]*\} ([\w-]+)\(", compiled.as_text())
+    assert not {"copy", "transpose"} & set(ops), ops
+    leaf_bytes = 2 * int(jnp.prod(jnp.array(shape)))
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes // 8

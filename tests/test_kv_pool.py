@@ -1,5 +1,8 @@
 """KVBlockPool allocator/refcount/arena unit tests (no model forwards) and
 the paged decode-attention kernel oracle checks."""
+import dataclasses
+import re
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,6 +12,7 @@ from repro.kernels import ops, ref
 from repro.models import LM
 from repro.models.layers import KVCache
 from repro.serving import KVBlockPool, PoolExhausted
+from repro.serving.kv_pool import _block_reader, _block_writer
 
 
 @pytest.fixture()
@@ -109,6 +113,109 @@ def test_write_gather_roundtrip(pool):
         assert (np.asarray(got.k[:, 0]) == np.asarray(k[:, r])).all()
         assert (np.asarray(got.v[:, 0]) == np.asarray(v[:, r])).all()
         assert got.k.shape == (n, 1, s, cfg.n_kv_heads, cfg.hd)
+
+
+def _bits(a):
+    return np.asarray(a).view(np.uint16)
+
+
+@pytest.mark.parametrize("lanes", [False, True], ids=["slices", "lanes"])
+@pytest.mark.parametrize("b,s,rows,start", [
+    (2, 21, 2, 0),          # start 0, partial last block zero-padded
+    (2, 29, 2, 16),         # block-aligned start > 0
+    (4, 21, 3, 0),          # bucket-dummy row (B > rows) dropped
+    (1, 21, 1, 0),          # 1 row
+    (2, 24, 2, 8),          # 2 rows, full last block
+    (4, 21, 4, 0),          # 4 rows
+], ids=["partial_block", "aligned_start", "dummy_rows", "one_row",
+        "two_rows", "four_rows"])
+def test_write_matches_eager_scatter(pool, lanes, b, s, rows, start):
+    """The pool's writer — both placements, whichever the arena's memory
+    layout picks — stores exactly the bits of an eager ``.at[:, ids].set``
+    of the zero-padded block slab, -0.0 and NaN included, and leaves every
+    other block (dummy 0 too) as it was."""
+    lm = LM(get_reduced("llama3-8b"))
+    cfg = lm.cfg
+    n, bs = cfg.pattern[0][1], pool.block_size
+    rng = np.random.default_rng(7)
+    shape = (n, b, s, cfg.n_kv_heads, cfg.hd)
+    k = rng.standard_normal(shape).astype(np.float32)
+    k.flat[::97] = -0.0
+    k.flat[5::211] = np.nan
+    k = jnp.asarray(k, jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    old = [jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+           for a in pool.arenas[0]]
+    pool.arenas[0] = type(pool.arenas[0])(*old)
+    nb = pool.blocks_for(s - start)
+    ids = rng.permutation(np.arange(1, pool.num_blocks))[:rows * nb]
+    row_blocks = [list(ids[r * nb:(r + 1) * nb]) for r in range(rows)]
+
+    def to_blocks(leaf):                     # the eager path, by hand
+        leaf = leaf[:, :rows, start:]
+        leaf = jnp.pad(leaf, ((0, 0), (0, 0), (0, nb * bs - (s - start)),
+                              (0, 0), (0, 0)))
+        return leaf.reshape(n, rows * nb, bs, *leaf.shape[3:])
+
+    want = [a.at[:, ids].set(to_blocks(x)) for a, x in zip(old, (k, v))]
+    pool._write_blocks = _block_writer([lanes])
+    pool.write([KVCache(k, v, jnp.broadcast_to(jnp.arange(s), (n, s)))],
+               row_blocks, start=start)
+    for got, exp in zip(pool.arenas[0], want):
+        assert (_bits(got) == _bits(exp)).all()
+    assert pool.total_writes == 1
+    assert pool.total_written_blocks == rows * nb
+
+
+@pytest.mark.parametrize("lanes", [False, True], ids=["slices", "lanes"])
+def test_gather_and_stash_match_eager_take(pool, lanes):
+    """The pool's reader — both placements — gives exactly the bits of an
+    eager ``jnp.take`` of the blocks, -0.0 and NaN included, to the dense
+    gather and to a stash alike."""
+    rng = np.random.default_rng(11)
+    old = []
+    for a in pool.arenas[0]:
+        x = rng.standard_normal(a.shape).astype(np.float32)
+        x.flat[::89] = -0.0
+        x.flat[3::173] = np.nan
+        old.append(jnp.asarray(x, a.dtype))
+    pool.arenas[0] = type(pool.arenas[0])(*old)
+    pool._read_blocks = _block_reader([lanes])
+    ids = [int(i) for i in rng.permutation(np.arange(pool.num_blocks))[:5]]
+    length = 5 * pool.block_size - 3
+    got = pool.gather_stacked(ids, length)[0]
+    stash = pool.stash_blocks(ids)[0]
+    for leaf, dense, stashed in zip(old, (got.k, got.v), stash):
+        want = jnp.take(leaf, jnp.asarray(ids), axis=1)
+        assert (_bits(stashed) == _bits(want)).all()
+        want = want.reshape(leaf.shape[0], 1, -1, *leaf.shape[3:])
+        assert (_bits(dense) == _bits(want[:, :, :length])).all()
+
+
+def test_write_is_in_place():
+    """The compiled writer aliases the donated arena to its result, and no
+    instruction but the parameters, their loop plumbing and in-place
+    dynamic-update-slices has the arena's shape: no copy of the arena.  A
+    float32 pool, as the CPU widens bf16 updates to float32 around them."""
+    lm = LM(dataclasses.replace(get_reduced("llama3-8b"), dtype="float32"))
+    pool = KVBlockPool(lm, num_blocks=64, block_size=8)
+    arena = pool.arenas[0].k
+    n = arena.shape[0]
+    k = jnp.zeros((n, 2, 21, *arena.shape[3:]), arena.dtype)
+    compiled = pool._write_blocks.lower(
+        pool.arenas, [(k, k)], np.arange(1, 7, dtype=np.int32), 0,
+        3).compile()
+    hlo = compiled.as_text()
+    assert re.search(r"input_output_alias=\{ \{0\}: \(0, \{\}, may-alias\), "
+                     r"\{1\}: \(1, \{\}, may-alias\) \}", hlo)
+    shape = f"f32[{','.join(map(str, arena.shape))}]"
+    ops_ = re.findall(re.escape(shape) + r"\{[^}]*\} ([\w-]+)\(", hlo)
+    assert set(ops_) <= {"parameter", "get-tuple-element",
+                         "dynamic-update-slice", "fusion"}, ops_
+    for root in re.findall(r"ROOT %\S+ = " + re.escape(shape)
+                           + r"\{[^}]*\} ([\w-]+)\(", hlo):
+        assert root in ("dynamic-update-slice", "tuple"), root
+    assert compiled.memory_analysis().temp_size_in_bytes < arena.nbytes
 
 
 def test_stash_unstash_roundtrip_is_bit_identical(pool):
