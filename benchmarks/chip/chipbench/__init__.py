@@ -1,11 +1,14 @@
 """The chip benchmark's yardstick: spec loading, traffic generation, the
-closed-loop driver, the window arithmetic, trace reduction, work counts,
-peaks and the plain float32 reference.
+closed-loop driver, the window arithmetic, trace reduction, the roofline,
+peaks and what every architecture's plain float32 reference shares.
 
 Everything a cell needs is found by name from ``BENCHMARK.json``:
-``configs/<config>.json``, ``traffic/<traffic>.json`` and one reader per
-metric in ``metrics/<metric>.py``.  Adding a cell, a configuration or a
-metric adds files and entries; it edits nothing here.
+``configs/<config>.json``, the architecture module that configuration
+names (``architectures/<architecture>.py``: its program check, sizes,
+reference, work counts and cache reading; ``model.py`` lists the
+contract), ``traffic/<traffic>.json`` and one reader per metric in
+``metrics/<metric>.py``.  Adding a cell, a configuration, an architecture
+or a metric adds files and entries; it edits nothing here.
 """
 from __future__ import annotations
 

@@ -32,8 +32,7 @@ from . import (BENCH_DIR, cell_metrics, find_cell, load_json, load_spec,
                use_program)
 from .check import RowSampler, compare
 from .instruments import CompileCounter, Instruments
-from .model import init_params, load_config, model_config
-from .reference import Reference, dims_from_config
+from .model import init_params, load_architecture, load_config, model_config
 from .traffic import ClientStream, load_traffic, prewarm_rounds
 from .window import stagger_ticks
 
@@ -107,6 +106,7 @@ def run_spec(cell: dict, cfg_spec: dict, mix: dict, spec: dict, seed: int,
     model from one run to the next in one process (calibration)."""
     name = cell["name"]
     t_enter = time.perf_counter()
+    arch = load_architecture(cfg_spec)
     use_program()
     gc.collect()            # a run before this one in the same process
     import jax
@@ -122,22 +122,23 @@ def run_spec(cell: dict, cfg_spec: dict, mix: dict, spec: dict, seed: int,
     from repro.models import LM
     cfg = model_config(cfg_spec, rehearsal)
     lm = shared.setdefault("lm", LM(cfg)) if shared is not None else LM(cfg)
-    params = init_params(lm, seed)
+    params = init_params(lm, seed, arch)
     jax.block_until_ready(params)
     t_init = time.perf_counter()
     c_init = counter.snapshot()
 
-    state = _serve(cell, cfg_spec, mix, lm, params, seed, seconds, trace,
-                   rehearsal, fault, counter, shared)
+    state = _serve(cell, cfg_spec, mix, arch, lm, params, seed, seconds,
+                   trace, rehearsal, fault, counter, shared)
     memory_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                       for d in jax.local_devices()[:cell["chips"]]) or None
     gc.collect()
 
-    dims = (dims_from_config(cfg_spec) if not rehearsal
-            else _dims_from_program(cfg))
+    dims = (arch.dims(cfg_spec) if not rehearsal
+            else arch.dims_from_program(cfg))
     t_ref = time.perf_counter()
-    reading = compare(state.rows, Reference(params, dims),
-                      Reference(params, dims, "fp8") if control else None)
+    reading = compare(state.rows, arch.Reference(params, dims, "float32"),
+                      arch.Reference(params, dims, "fp8") if control
+                      else None)
     reading["reference_s"] = time.perf_counter() - t_ref
 
     limits = cfg_spec["rehearsal" if rehearsal else "check"]["limits"]
@@ -152,8 +153,8 @@ def run_spec(cell: dict, cfg_spec: dict, mix: dict, spec: dict, seed: int,
     kind = jax.devices()[0].device_kind
     # what a metric reader (metrics/<name>.py) is given
     record = SimpleNamespace(
-        cell=cell, mix=mix, dims=dims, seconds=seconds, state=state,
-        peak=peaks.get(kind), setup_s=state.t_open - t_start)
+        cell=cell, mix=mix, arch=arch, dims=dims, seconds=seconds,
+        state=state, peak=peaks.get(kind), setup_s=state.t_open - t_start)
     group = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in cell_metrics(spec, name, group):
@@ -195,15 +196,8 @@ def run_spec(cell: dict, cfg_spec: dict, mix: dict, spec: dict, seed: int,
     return result, details
 
 
-def _dims_from_program(cfg) -> dict:
-    return dict(d=cfg.d_model, layers=cfg.n_layers, heads=cfg.n_heads,
-                kv=cfg.n_kv_heads, ff=cfg.d_ff, vocab=cfg.vocab_size,
-                theta=float(cfg.rope_theta), eps=float(cfg.norm_eps),
-                tie=bool(cfg.tie_embeddings))
-
-
-def _serve(cell, cfg_spec, mix, lm, params, seed, seconds, trace, rehearsal,
-           fault, counter, shared) -> SimpleNamespace:
+def _serve(cell, cfg_spec, mix, arch, lm, params, seed, seconds, trace,
+           rehearsal, fault, counter, shared) -> SimpleNamespace:
     """Build the engine, drive warm-up, window and drain, and return what
     the metrics and the check read; the program's state is dropped when
     this returns."""
@@ -239,7 +233,8 @@ def _serve(cell, cfg_spec, mix, lm, params, seed, seconds, trace, rehearsal,
     c_prewarm = counter.snapshot()
     check = cfg_spec["check"]
     sampler = RowSampler(seed, check["rows_per_class"])
-    ins = Instruments(engine, sched, sampler, clock=clock, tracing=trace)
+    ins = Instruments(engine, sched, arch, sampler, clock=clock,
+                      tracing=trace)
     ins.attach()
 
     n = mix["clients"]
@@ -301,7 +296,7 @@ def _serve(cell, cfg_spec, mix, lm, params, seed, seconds, trace, rehearsal,
                 stats_open, c_open = ins.stats(), counter.snapshot()
         elif phase == "window":
             if trace and tracer is None and now >= t_close - TRACE_SECONDS:
-                tracer = _Tracer(ins, trunk_marker(lm.cfg))
+                tracer = _Tracer(ins, arch.trunk_marker(lm.cfg))
             if now >= t_close:
                 phase = ins.phase = "drain"
                 stats_close, c_close = ins.stats(), counter.snapshot()
@@ -355,13 +350,6 @@ def _serve(cell, cfg_spec, mix, lm, params, seed, seconds, trace, rehearsal,
     del clients, done, counted, ex, sched, engine, ins, submit, tracer
     gc.collect()
     return state
-
-
-def trunk_marker(cfg) -> str:
-    """Text that the HLO of a program running the model's layer stack holds:
-    the shape of its stacked feed-forward weights.  The probe prefill
-    programs are the executions that run such an operation."""
-    return f"[{cfg.n_layers},{cfg.d_model},{cfg.d_ff}]"
 
 
 class _Tracer:

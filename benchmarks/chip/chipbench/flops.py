@@ -1,70 +1,14 @@
-"""Operations and bytes of a probe prefill, from its logical shapes.
+"""The roofline of a program call from its operations and bytes.
 
-A probe prefill program runs ``rows`` rows of ``new`` tokens each over
-``cached`` tokens of key/value already held (0 for a plain prefill, the
-prefix length for a prefill continued from the prefix cache), returns the
-key/value of all ``cached + new + reserve`` positions, and reads out the
-logits of each row's last position.  ``dims`` are the sizes of
-``reference.dims_from_config``.
-
-Counted operations are multiply-adds times two: the projections and the
-feed-forward for every new token, attention as computed (every new query
-against every cached and new key, the causal half included, since the
-program computes the whole rectangle), and the head for one position a row.
+The operations and bytes themselves depend on the architecture: each
+architecture module counts them (``prefill_flops``, ``prefill_bytes`` and
+``model_flops`` of ``architectures/<name>.py``) from a call's logical
+shapes, with the byte sizes below.
 """
 from __future__ import annotations
 
 BF16 = 2
 LOGIT_BYTES = 2       # the head's bf16 output
-
-
-def head_dim(dims: dict) -> int:
-    return dims["d"] // dims["heads"]
-
-
-def layer_params(dims: dict) -> int:
-    """Weights of one decoder layer's matmuls."""
-    d, h, kv, f = dims["d"], dims["heads"], dims["kv"], dims["ff"]
-    hd = head_dim(dims)
-    return d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
-
-
-def head_params(dims: dict) -> int:
-    return dims["d"] * dims["vocab"]
-
-
-def kv_bytes_per_token(dims: dict) -> int:
-    return dims["layers"] * 2 * dims["kv"] * head_dim(dims) * BF16
-
-
-def prefill_flops(dims: dict, rows: int, new: int, cached: int = 0) -> int:
-    """Operations of a prefill of ``rows`` rows as the device computes it."""
-    linear = 2 * layer_params(dims) * dims["layers"] * rows * new
-    attn = (4 * dims["layers"] * dims["heads"] * head_dim(dims)
-            * rows * new * (cached + new))
-    return linear + attn + 2 * head_params(dims) * rows
-
-
-def prefill_bytes(dims: dict, rows: int, new: int, cached: int = 0,
-                  reserve: int = 0) -> int:
-    """Bytes a prefill must move at least: every weight once (the
-    embedding's gathered rows only), the cached key/value read, the
-    key/value of every position written, and the logits written."""
-    weights = (layer_params(dims) * dims["layers"] + head_params(dims)) * BF16
-    embed_rows = rows * new * dims["d"] * BF16
-    kv = kv_bytes_per_token(dims) * rows * (cached + (cached + new + reserve))
-    logits = rows * dims["vocab"] * LOGIT_BYTES
-    return weights + embed_rows + kv + logits
-
-
-def model_flops(dims: dict, live_tokens: int, live_rows: int,
-                attn_pairs: int) -> int:
-    """Model operations of the live, unpadded work: the projections and the
-    feed-forward of each live token, attention over ``attn_pairs`` (query,
-    key) pairs of live tokens, and the head for each live row."""
-    return (2 * layer_params(dims) * dims["layers"] * live_tokens
-            + 4 * dims["layers"] * dims["heads"] * head_dim(dims) * attn_pairs
-            + 2 * head_params(dims) * live_rows)
 
 
 def roofline_seconds(flops: int, nbytes: int, peak: dict) -> tuple[float, str]:

@@ -82,6 +82,7 @@ class ProgramCall:
 class Instruments:
     engine: object
     sched: object
+    arch: object                       # the configuration's architecture
     sampler: object = None
     clock: object = time.perf_counter
     tracing: bool = False
@@ -138,7 +139,7 @@ class Instruments:
             self._tokens = None
             cached = 0
             if kind == "prefill_cont":
-                cached = int(args[1][0].k.shape[2])
+                cached = self.arch.cached_positions(args[1])
             if toks is not None and self.phase == "window":
                 self.calls.append(program_call(kind, self.clock(), toks,
                                                cached, reserve,

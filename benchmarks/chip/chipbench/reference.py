@@ -1,36 +1,26 @@
-"""The plain reference: a float32 forward pass of a dense decoder, written
-from the architecture's equations with nothing of the program imported.
+"""What every architecture's plain reference shares: a float32 forward pass
+written from the architecture's equations with nothing of the program
+imported.  Each architecture module (``architectures/<name>.py``) writes
+its own layer and head on these pieces; the dense decoder's equations are
+in ``architectures/dense.py``.
 
-It reads the benchmark's own weights (``model.init_params``) by their names
-in the parameter tree, upcasts each layer's bf16 weights to float32 exactly,
-and computes every contraction at ``Precision.HIGHEST``, layer by layer, in
-fixed blocks of rows, so that it fits beside the weights once the program's
-state is freed.
-
-The architecture it follows is the one the configuration files run, with
-their departures from the published models (see each file's
-``departures``): RMSNorm with gain ``1 + g``; full rotary embedding on the
-whole head, halves rotated (``x1 cos - x2 sin, x2 cos + x1 sin``); grouped
-query attention, query head ``j`` reading key/value head ``j // (H / KV)``;
-causal attention over every position, the left padding included (the
-program's prompts carry no padding mask, so a row's logits are defined at
-its padded length); SwiGLU feed-forward; no biases; logits of the last
-position from the final RMSNorm and the head (the embedding, transposed,
-where the configuration ties them).
+A reference reads the benchmark's own weights (``model.init_params``) by
+their names in the parameter tree, upcasts each layer's bf16 weights to
+float32 exactly, and computes every contraction at ``Precision.HIGHEST``,
+layer by layer, in fixed blocks of rows (:class:`RowBlocked`), so that it
+fits beside the weights once the program's state is freed.
 
 Prompts are byte-tokenised: ``BOS`` then the UTF-8 bytes of the whole
 prompt (a structured ``(prefix, suffix)`` prompt is their concatenation),
 left-padded with ``PAD`` to the next power of two of at least 16 tokens.
 
 ``precision="fp8"`` is the control: every operand of every weight matmul
-(the projections, the feed-forward and the head) is rounded to float8
-e4m3, weights with one scale per matrix and activations with one scale per
-token, before a float32 product.
+(``_mm``: the projections, the feed-forward and the head) is rounded to
+float8 e4m3, weights with one scale per matrix and activations with one
+scale per token, before a float32 product.
 """
 from __future__ import annotations
 
-import math
-from functools import partial
 from typing import Sequence
 
 import numpy as np
@@ -57,27 +47,20 @@ def padded_row(ids: Sequence[int]) -> np.ndarray:
     return row
 
 
-def dims_from_config(spec: dict) -> dict:
-    """The sizes the reference needs, from a configuration file's published
-    keys."""
-    eps = spec.get("rms_norm_eps", spec.get("layer_norm_eps"))
-    return dict(d=spec["hidden_size"], layers=spec["num_hidden_layers"],
-                heads=spec["num_attention_heads"],
-                kv=spec["num_key_value_heads"], ff=spec["intermediate_size"],
-                vocab=spec["vocab_size"], theta=float(spec["rope_theta"]),
-                eps=float(eps), tie=bool(spec["tie_word_embeddings"]))
+class RowBlocked:
+    """What every architecture's reference shares: the weights, the sizes,
+    the precision, the embedding, and :meth:`logits`, which runs the rows
+    in fixed blocks.  An architecture's ``Reference`` subclasses it and
+    gives ``_block``: the last-position logits (B, V) of ``ROW_BLOCK``
+    padded rows of one length."""
 
-
-class Reference:
     def __init__(self, params, dims: dict, precision: str = "float32"):
         import jax
         if precision not in ("float32", "fp8"):
             raise ValueError(precision)
         self.params = params
         self.dims = dims
-        fp8 = precision == "fp8"
-        self._layer = jax.jit(partial(_layer, dims=dims, fp8=fp8))
-        self._head = jax.jit(partial(_head, dims=dims, fp8=fp8))
+        self.fp8 = precision == "fp8"
         self._embed = jax.jit(_embed)
 
     def logits(self, rows: Sequence[np.ndarray]) -> np.ndarray:
@@ -96,14 +79,7 @@ class Reference:
         return out
 
     def _block(self, toks: np.ndarray) -> np.ndarray:
-        import jax.numpy as jnp
-        p = self.params
-        stack = p["stacks"][0]
-        x = self._embed(p["embed"], jnp.asarray(toks))
-        for i in range(self.dims["layers"]):
-            x = self._layer(x, stack, jnp.int32(i))
-        head = p["embed"] if self.dims["tie"] else p["lm_head"]
-        return np.asarray(self._head(x[:, -1], p["final_norm"], head))
+        raise NotImplementedError
 
 
 # ------------------------------------------------------------ the equations
@@ -148,37 +124,3 @@ def _rope(x, theta):
     sin = jnp.sin(ang)[None, :, None, :]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _layer(x, stack, i, *, dims, fp8):
-    import jax
-    import jax.numpy as jnp
-    hi = jax.lax.Precision.HIGHEST
-    w = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False),
-                     stack)
-    b, s, d = x.shape
-    h, kv = dims["heads"], dims["kv"]
-    hd = d // h
-    a = _rms(x, w["norm1"], dims["eps"])
-    q = _rope(_mm(a, w["wq"], fp8).reshape(b, s, h, hd), dims["theta"])
-    k = _rope(_mm(a, w["wk"], fp8).reshape(b, s, kv, hd), dims["theta"])
-    v = _mm(a, w["wv"], fp8).reshape(b, s, kv, hd)
-    k = jnp.repeat(k, h // kv, axis=2)
-    v = jnp.repeat(v, h // kv, axis=2)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=hi) / math.sqrt(hd)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(causal, scores, -jnp.inf)
-    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v,
-                   precision=hi)
-    x = x + _mm(o.reshape(b, s, h * hd), w["wo"], fp8)
-    a = _rms(x, w["norm2"], dims["eps"])
-    f = w["ffn"]
-    g = _mm(a, f["w_gate"], fp8)
-    u = _mm(a, f["w_up"], fp8)
-    return x + _mm(jax.nn.silu(g) * u, f["w_down"], fp8)
-
-
-def _head(x_last, final_norm, head, *, dims, fp8):
-    """x_last (B, d) -> logits (B, V)."""
-    a = _rms(x_last, final_norm, dims["eps"])
-    return _mm(a, head.T if dims["tie"] else head, fp8)

@@ -4,7 +4,8 @@
     python3 benchmarks/chip/run.py --workload <config>.<traffic> \
         --seed <n> --seconds <s> --trace <0|1>
 
-The cell, its configuration (``configs/``), its traffic mix (``traffic/``)
+The cell, its configuration (``configs/``), the architecture module that
+configuration names (``architectures/``), its traffic mix (``traffic/``)
 and its metrics (one reader each in ``metrics/``) are found by name from
 ``BENCHMARK.json``.  With ``--trace 0`` the result carries the cell's
 end-to-end metrics, with ``--trace 1`` its per-layer metrics read from a
